@@ -8,8 +8,7 @@ slots, 512 arrivals per tick, rho = 0.8, the ``storm`` scenario, memory
 dynamics and Airlock on, the ``balanced`` tier mix — for 1,000 ticks (0.5 s
 of simulated time: the 150 ms probe TTL turns over 3.3 times and the 120 ms
 survival TTL 4.2 times). Only the horizon is cut, to keep the whole script
-well inside its 1,200 s on one v5e chip, where the jnp scan ran at about
-52 ms per tick and two vmapped seeds at about 136 ms.
+well inside its 1,200 s on one v5e chip.
 
   (a) ``LaminarEngine.run`` on the default jnp path;
   (b) ``LaminarEngine.run_batch`` over seeds 0 and 1 on the default path;
@@ -22,10 +21,9 @@ engine on one, at 32,000 nodes (131,072 slots) for 200 ticks, and requires
 equal integer counters. Every run must satisfy the task-conservation
 identity, start work, and (Airlock) suspend.
 
-Compile and run seconds printed here are smoke timings of one cold run each,
-not benchmark numbers. The script exits non-zero, printing no result, unless
-JAX's first device is a TPU; the last line of stdout is one JSON object
-naming the device.
+It times nothing: the benchmark (``bench/run.py``) measures speed. The
+script exits non-zero, printing no result, unless JAX's first device is a
+TPU; the last line of stdout is one JSON object naming the device.
 """
 
 from __future__ import annotations
@@ -34,7 +32,6 @@ import argparse
 import dataclasses
 import json
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -44,37 +41,6 @@ MAIN_TICKS = 1000
 MESH_NODES = 32000
 MESH_TICKS = 200
 MESH_DEVICES = 4
-COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
-)
-
-
-class CompileClock:
-    """Seconds JAX spent tracing, lowering and compiling, summed."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **_) -> None:
-        if event in COMPILE_EVENTS:
-            self.seconds += duration
-
-    def timed(self, label: str, fn):
-        """Call ``fn``; print its compile seconds and the rest of its wall."""
-        c0, t0 = self.seconds, time.perf_counter()
-        out = fn()
-        wall = time.perf_counter() - t0
-        compile_s = self.seconds - c0
-        print(
-            f"{label}: compile_s={compile_s} run_s={wall - compile_s} "
-            "(smoke timing: one cold call; run_s includes init and summarize)"
-        )
-        return out
 
 
 def int_counters(out: dict) -> dict:
@@ -153,26 +119,19 @@ def smoke_cfg(num_nodes: int | None = None):
     )
 
 
-def run_one_chip(cfg, num_ticks: int, clock: CompileClock) -> None:
+def run_one_chip(cfg, num_ticks: int) -> None:
     from repro.core import LaminarEngine
 
-    ref = clock.timed(
-        "jnp run", lambda: LaminarEngine(cfg).run(seed=0, num_ticks=num_ticks)
-    )
+    ref = LaminarEngine(cfg).run(seed=0, num_ticks=num_ticks)
     check_run("jnp run", ref, cfg.airlock)
 
-    outs = clock.timed(
-        "jnp run_batch",
-        lambda: LaminarEngine(cfg).run_batch([0, 1], num_ticks=num_ticks),
-    )
+    outs = LaminarEngine(cfg).run_batch([0, 1], num_ticks=num_ticks)
     for out in outs:
         check_run(f"run_batch seed {out['seed']}", out, cfg.airlock)
     print_peak_memory("after run_batch")
 
     pal_engine = LaminarEngine(dataclasses.replace(cfg, use_pallas=True))
-    pal = clock.timed(
-        "pallas run", lambda: pal_engine.run(seed=0, num_ticks=num_ticks)
-    )
+    pal = pal_engine.run(seed=0, num_ticks=num_ticks)
     check_run("pallas run", pal, cfg.airlock)
     if "tpu_custom_call" not in lowered_text(pal_engine, num_ticks):
         raise RuntimeError("pallas run: no tpu_custom_call in its program")
@@ -181,21 +140,15 @@ def run_one_chip(cfg, num_ticks: int, clock: CompileClock) -> None:
     print_peak_memory("after pallas run")
 
 
-def run_four_chips(cfg, num_ticks: int, devices: int, clock: CompileClock) -> None:
+def run_four_chips(cfg, num_ticks: int, devices: int) -> None:
     from repro.core import LaminarEngine
     from repro.parallel.engine_mesh import ZoneShardedEngine
 
     mesh_engine = ZoneShardedEngine(cfg, num_devices=devices)
     print(f"traffic_model: {json.dumps(mesh_engine.traffic(seed=0))}")
-    mesh = clock.timed(
-        f"sharded run ({devices} devices)",
-        lambda: mesh_engine.run(seed=0, num_ticks=num_ticks),
-    )
+    mesh = mesh_engine.run(seed=0, num_ticks=num_ticks)
     check_run("sharded run", mesh, cfg.airlock)
-    flat = clock.timed(
-        "flat run (1 device)",
-        lambda: LaminarEngine(cfg).run(seed=0, num_ticks=num_ticks),
-    )
+    flat = LaminarEngine(cfg).run(seed=0, num_ticks=num_ticks)
     check_run("flat run", flat, cfg.airlock)
     require_equal("sharded vs flat", mesh, flat)
     print_peak_memory("after runs")
@@ -229,11 +182,10 @@ def main() -> None:
 
     print(f"device_kind={dev.device_kind} count={count}")
     print(f"compile cache: {compile_cache.enable()}")
-    clock = CompileClock()
     if args.chips == MESH_DEVICES:
-        run_four_chips(smoke_cfg(MESH_NODES), MESH_TICKS, MESH_DEVICES, clock)
+        run_four_chips(smoke_cfg(MESH_NODES), MESH_TICKS, MESH_DEVICES)
     else:
-        run_one_chip(smoke_cfg(), MAIN_TICKS, clock)
+        run_one_chip(smoke_cfg(), MAIN_TICKS)
     print(
         json.dumps(
             {

@@ -50,6 +50,29 @@ TS_FIELDS = (
 )
 
 
+# The stages of the tick, in tick order. ``make_step`` runs each inside
+# ``jax.named_scope("laminar.<stage>")``, so every device op of the tick
+# carries its stage in its ``op_name`` metadata; work outside them (the key
+# split, the tick counter, the timeseries row) is unscoped. The scopes add
+# metadata only, no operations.
+STAGES = (
+    "survival",
+    "completions",
+    "disruption",
+    "dissemination",
+    "intake",
+    "addressing",
+    "arbitration",
+)
+SCOPE_PREFIX = "laminar."
+
+
+def _stage(name: str):
+    if name not in STAGES:
+        raise ValueError(f"unknown tick stage {name!r}")
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
 def _inject_arrivals(
     cfg: LaminarConfig,
     s: SimState,
@@ -163,60 +186,70 @@ def make_step(
 
         # ---- runtime survival (Exp5) ---------------------------------------
         if cfg.memory.enabled:
-            s = airlock.memory_dynamics(cfg, s, ks[1])
-            # one fused pass over the probe table: pressure + victim +
-            # transition masks (jnp reference or Pallas survival_scan kernel)
-            pressure, victim, resume, react, expire = hotpath.survival_scan(cfg, s)
-            s = airlock.runtime_control(cfg, s, victim)
-            s, react_mask = airlock.airlock_transitions(cfg, s, resume, react, expire)
+            with _stage("survival"):
+                s = airlock.memory_dynamics(cfg, s, ks[1])
+                # one fused pass over the probe table: pressure + victim +
+                # transition masks (jnp reference or Pallas survival_scan kernel)
+                pressure, victim, resume, react, expire = hotpath.survival_scan(cfg, s)
+                s = airlock.runtime_control(cfg, s, victim)
+                s, react_mask = airlock.airlock_transitions(
+                    cfg, s, resume, react, expire
+                )
         else:
             pressure = jnp.zeros((cfg.num_nodes,), jnp.float32)
             react_mask = jnp.zeros_like(s.migrating)
 
         # ---- service progress ------------------------------------------------
-        s = arbiter.completions(cfg, s)
+        with _stage("completions"):
+            s = arbiter.completions(cfg, s)
 
         # ---- scenario disruption: fail/drain/recover nodes --------------------
         if disruption_on:
-            s, evict_mask = disrupt.apply(cfg, scenario, s, ks[7])
+            with _stage("disruption"):
+                s, evict_mask = disrupt.apply(cfg, scenario, s, ks[7])
         else:
             evict_mask = jnp.zeros_like(s.migrating)
 
-        # ---- true node state, computed once per tick ---------------------------
-        if plane is None:
-            view = zhaf.build_view(cfg, s)
-            bits = view.bits
-        else:
-            view, bits = plane.build_view(cfg, s)
+        with _stage("dissemination"):
+            # ---- true node state, computed once per tick -----------------------
+            if plane is None:
+                view = zhaf.build_view(cfg, s)
+                bits = view.bits
+            else:
+                view, bits = plane.build_view(cfg, s)
 
-        # ---- cold path: state dissemination -------------------------------
-        s = zhaf.report(cfg, s, ks[0], view)
-        s = teg.refresh(cfg, s, plane)
+            # ---- cold path: state dissemination ---------------------------
+            s = zhaf.report(cfg, s, ks[0], view)
+            s = teg.refresh(cfg, s, plane)
 
         # ---- admissions hot path ----------------------------------------------
-        if sched.kind == "stationary":
-            lam_t = lam_per_tick  # exact pre-scenario arrival stream
-        else:
-            lam_t = wl_schedule.rate_per_tick(
-                sched, lam_per_tick, s.t, s.sched_key, cfg.dt_ms
-            )
-        s, arrival_mask = _inject_arrivals(cfg, s, ks[2], lam_t)
-        s, regen_mask = da.move(cfg, s, ks[3])
-        dispatch_mask = arrival_mask | regen_mask | react_mask | evict_mask
-        s = teg.dispatch(cfg, s, ks[4], dispatch_mask, max_dispatch)
-        s = da.address(cfg, s, ks[5], view)
+        with _stage("intake"):
+            if sched.kind == "stationary":
+                lam_t = lam_per_tick  # exact pre-scenario arrival stream
+            else:
+                lam_t = wl_schedule.rate_per_tick(
+                    sched, lam_per_tick, s.t, s.sched_key, cfg.dt_ms
+                )
+            s, arrival_mask = _inject_arrivals(cfg, s, ks[2], lam_t)
+            s, regen_mask = da.move(cfg, s, ks[3])
+            dispatch_mask = arrival_mask | regen_mask | react_mask | evict_mask
+            s = teg.dispatch(cfg, s, ks[4], dispatch_mask, max_dispatch)
+        with _stage("addressing"):
+            s = da.address(cfg, s, ks[5], view)
 
-        throttled = (
-            (pressure > cfg.memory.high_watermark)
-            if (cfg.memory.enabled and cfg.airlock)
-            else jnp.zeros((cfg.num_nodes,), jnp.bool_)
-        )
-        # multiple admission rounds per tick: after each reservation the node
-        # removes the winner's atoms and proceeds to the next feasible candidate
-        for _ in range(cfg.arb_rounds):
-            s, bits = arbiter.arbitrate(cfg, s, ks[6], throttled, bits, plane)
-        s = arbiter.pending_stage(cfg, s)
-        s = arbiter.timeouts(cfg, s)
+        with _stage("arbitration"):
+            throttled = (
+                (pressure > cfg.memory.high_watermark)
+                if (cfg.memory.enabled and cfg.airlock)
+                else jnp.zeros((cfg.num_nodes,), jnp.bool_)
+            )
+            # multiple admission rounds per tick: after each reservation the
+            # node removes the winner's atoms and proceeds to the next feasible
+            # candidate
+            for _ in range(cfg.arb_rounds):
+                s, bits = arbiter.arbitrate(cfg, s, ks[6], throttled, bits, plane)
+            s = arbiter.pending_stage(cfg, s)
+            s = arbiter.timeouts(cfg, s)
 
         s = s._replace(t=s.t + 1)
         ts = jnp.stack([getattr(s.metrics, f) for f in TS_FIELDS])
