@@ -39,13 +39,12 @@ from repro.core.state import (
 from repro.core.utility import unified_utility
 
 
-def _dissipate_st(s: SimState, mask: jax.Array) -> jax.Array:
+def _dissipate_st(st: jax.Array, migrating: jax.Array, mask: jax.Array) -> jax.Array:
     """Kill the control incarnation. A migrating DA reverts to the suspended
     glass-state (the task still awaits T_surv reclamation at the source); an
     ordinary probe's slot is freed."""
-    st = jnp.where(mask & s.migrating, SUSPENDED, s.st)
-    st = jnp.where(mask & ~s.migrating, EMPTY, st)
-    return st
+    st = jnp.where(mask & migrating, SUSPENDED, st)
+    return jnp.where(mask & ~migrating, EMPTY, st)
 
 
 def move(cfg: LaminarConfig, s: SimState, key: jax.Array) -> Tuple[SimState, jax.Array]:
@@ -76,7 +75,7 @@ def move(cfg: LaminarConfig, s: SimState, key: jax.Array) -> Tuple[SimState, jax
         st = jnp.where(lost, LOST_WAIT, st)
         timer = jnp.where(lost, cfg.ticks(cfg.regen_quiet_ms), timer)
     else:
-        st = jnp.where(lost, _dissipate_st(s, lost), st)
+        st = _dissipate_st(st, s.migrating, lost)
         m = m._replace(lost=m.lost + jnp.sum(lost.astype(jnp.int32)))
 
     # regeneration: quiet interval elapsed -> respawn via TEG (fresh patience,
@@ -84,7 +83,7 @@ def move(cfg: LaminarConfig, s: SimState, key: jax.Array) -> Tuple[SimState, jax
     quiet_done = (s.st == LOST_WAIT) & (timer <= 0)
     can_regen = quiet_done & (s.regen < cfg.regen_cap)
     exhausted = quiet_done & ~can_regen
-    st = jnp.where(exhausted, _dissipate_st(s, exhausted), st)
+    st = _dissipate_st(st, s.migrating, exhausted)
 
     regen = jnp.where(can_regen, s.regen + 1, s.regen)
     patience = jnp.where(can_regen, s.ev, s.patience)
@@ -101,44 +100,52 @@ def move(cfg: LaminarConfig, s: SimState, key: jax.Array) -> Tuple[SimState, jax
     return s, can_regen
 
 
-def address(
-    cfg: LaminarConfig, s: SimState, key: jax.Array, view: zhaf.NodeView
-) -> SimState:
-    """One bounded addressing round for every kinetic DA (st == ADDRESSING).
+def address_block(cfg: LaminarConfig, num_slots: int) -> int:
+    """Slots ``address`` decides per trip of its block loop.
 
-    Candidate 0 is the current launchpad; k-1 more are sampled uniformly inside
-    the Zone. Scores come from the projected Z-HAF field; the stale-view
-    feasibility mask (S / max-run vs demand) prunes false candidates. If j* is
-    the launchpad we enqueue locally; otherwise one physical bounce.
+    The smallest multiple of the utility_topk kernel's probe block that
+    holds twice a tick's arrival cap, capped at the table: one trip covers
+    a steady tick's addressing DAs, and a busier tick takes more trips.
     """
-    P = s.st.shape[0]
-    k = cfg.candidate_k
-    k_cand, k_noise = jax.random.split(key)
+    want = max(2 * cfg.max_arrivals_per_tick, 1)
+    return min(-(-want // hotpath.TOPK_BLOCK) * hotpath.TOPK_BLOCK, num_slots)
 
-    active = s.st == ADDRESSING
 
-    zc = jnp.maximum(s.zcount[s.zone], 1).astype(jnp.float32)
-    r = jax.random.uniform(k_cand, (P, k - 1))
-    cand = s.zstart[s.zone][:, None] + jnp.floor(r * zc[:, None]).astype(jnp.int32)
+def _decide(
+    cfg: LaminarConfig,
+    s: SimState,
+    view: zhaf.NodeView,
+    slot: jax.Array,
+    r: jax.Array,
+    eps: jax.Array,
+):
+    """Addressing decisions of the kinetic DAs in ``slot``.
+
+    Returns their new (st, node, zone, timer, patience) and the bounce and
+    Fast-Fail masks. Each row reads only its own slot and the node tables,
+    so a DA's decision does not depend on which other slots are decided.
+    """
+    zone_r, node_r = s.zone[slot], s.node[slot]
+    zc = jnp.maximum(s.zcount[zone_r], 1).astype(jnp.float32)
+    cand = s.zstart[zone_r][:, None] + jnp.floor(r * zc[:, None]).astype(jnp.int32)
     cand = jnp.clip(cand, 0, cfg.num_nodes - 1)
-    cand = jnp.concatenate([jnp.maximum(s.node, 0)[:, None], cand], axis=1)
+    here = jnp.maximum(node_r, 0)
+    cand = jnp.concatenate([here[:, None], cand], axis=1)
 
     s_eff, h_eff, run_eff = zhaf.project(cfg, s, cand)
     # Candidate 0 is the node the DA is physically standing on: its local
     # T_zone replica is exact for itself (no staleness), so the launchpad is
     # evaluated against TRUE local state — stale false-optimism can only come
     # from remote candidates and is finally rejected at arbitration.
-    here = jnp.maximum(s.node, 0)
     s_eff = s_eff.at[:, 0].set(view.s_true[here])
     h_eff = h_eff.at[:, 0].set(view.h_true[here])
     run_eff = run_eff.at[:, 0].set(view.run_true[here])
-    mass_f = s.mass.astype(jnp.float32)[:, None]
-    feas = jnp.where(s.contig[:, None], run_eff >= mass_f, s_eff >= mass_f)
+    mass_f = s.mass[slot].astype(jnp.float32)[:, None]
+    feas = jnp.where(s.contig[slot][:, None], run_eff >= mass_f, s_eff >= mass_f)
 
     # fused utility scoring + candidate argmax: the paper's 13.7 ns hot-path
     # op. Symmetry-breaking noise is pre-sampled so kernel and reference see
     # the same eps (Addr_jk = log2(1+S) - gamma*log2(1+H) + eps, masked).
-    eps = cfg.addr_noise_sigma * jax.random.normal(k_noise, s_eff.shape)
     best, best_score = hotpath.utility_topk(
         cfg, s_eff, h_eff, eps, feas, cfg.gamma_repulsion
     )
@@ -155,29 +162,83 @@ def address(
         -jnp.inf,
     )
     prefer_here = here_ok & (best_score <= here_score + cfg.stay_margin)
-    target = jnp.where(prefer_here, jnp.maximum(s.node, 0), target)
+    target = jnp.where(prefer_here, here, target)
 
-    stay = active & any_feas & (target == s.node)
-    bounce = active & any_feas & (target != s.node)
+    stay = any_feas & (target == node_r)
+    bounce = any_feas & (target != node_r)
 
-    patience = jnp.where(active, s.patience - cfg.eval_cost, s.patience)
+    patience = s.patience[slot] - cfg.eval_cost
     patience = jnp.where(bounce, patience - cfg.bounce_cost, patience)
 
-    st = jnp.where(stay, QUEUED, s.st)
+    st = jnp.where(stay, QUEUED, ADDRESSING)
     st = jnp.where(bounce, BOUNCING, st)
-    node = jnp.where(bounce, target, s.node)
-    timer = jnp.where(bounce, 1, s.timer)  # single hop
-    zone = jnp.where(bounce, s.zone_id[target], s.zone)
+    node = jnp.where(bounce, target, node_r)
+    timer = jnp.where(bounce, 1, s.timer[slot])  # single hop
+    zone = jnp.where(bounce, s.zone_id[target], zone_r)
 
     # Fast-Fail: patience below the floor dissipates the probe locally.
-    ff = active & (patience < cfg.fastfail_floor)
-    st = jnp.where(ff, _dissipate_st(s._replace(st=st), ff), st)
+    ff = patience < cfg.fastfail_floor
+    st = _dissipate_st(st, s.migrating[slot], ff)
+    return (st, node, zone, timer, patience), bounce, ff
+
+
+def address(
+    cfg: LaminarConfig, s: SimState, key: jax.Array, view: zhaf.NodeView
+) -> SimState:
+    """One bounded addressing round for every kinetic DA (st == ADDRESSING).
+
+    Candidate 0 is the current launchpad; k-1 more are sampled uniformly inside
+    the Zone. Scores come from the projected Z-HAF field; the stale-view
+    feasibility mask (S / max-run vs demand) prunes false candidates. If j* is
+    the launchpad we enqueue locally; otherwise one physical bounce.
+
+    Only the addressing slots are decided: their indices are compacted from
+    a cumulative count and decided ``address_block`` at a time, as many
+    trips as the count needs. The random numbers are drawn for the whole
+    table, so every slot sees the numbers it would see were all decided.
+    """
+    P = s.st.shape[0]
+    k = cfg.candidate_k
+    k_cand, k_noise = jax.random.split(key)
+    r = jax.random.uniform(k_cand, (P, k - 1))
+    eps = cfg.addr_noise_sigma * jax.random.normal(k_noise, (P, k))
+
+    rank = jnp.cumsum((s.st == ADDRESSING).astype(jnp.int32))
+    n_active = rank[-1]
+    C = address_block(cfg, P)
+    lane_rank = jnp.arange(1, C + 1, dtype=jnp.int32)
+
+    def more(carry):
+        return carry[0] * C < n_active
+
+    def trip(carry):
+        b, fields, n_bounce, n_ff = carry
+        # the slot of each rank in this block; P past the last addressing DA
+        slot = jnp.searchsorted(
+            rank, b * C + lane_rank, side="left", method="scan_unrolled"
+        ).astype(jnp.int32)
+        valid = slot < P
+        row = jnp.minimum(slot, P - 1)  # gathers only; scatters drop P
+        new, bounce, ff = _decide(cfg, s, view, row, r[row], eps[row])
+        fields = tuple(
+            f.at[slot].set(v, mode="drop") for f, v in zip(fields, new)
+        )
+        n_bounce = n_bounce + jnp.sum((valid & bounce & ~ff).astype(jnp.int32))
+        n_ff = n_ff + jnp.sum((valid & ff).astype(jnp.int32))
+        return b + 1, fields, n_bounce, n_ff
+
+    zero = jnp.zeros((), jnp.int32)
+    fields = (s.st, s.node, s.zone, s.timer, s.patience)
+    _, fields, n_bounce, n_ff = jax.lax.while_loop(
+        more, trip, (zero, fields, zero, zero)
+    )
+    st, node, zone, timer, patience = fields
 
     m = s.metrics
     m = m._replace(
-        op_eval=m.op_eval + jnp.sum(active.astype(jnp.int32)),
-        op_bounce=m.op_bounce + jnp.sum((bounce & ~ff).astype(jnp.int32)),
-        fastfail=m.fastfail + jnp.sum(ff.astype(jnp.int32)),
+        op_eval=m.op_eval + n_active,
+        op_bounce=m.op_bounce + n_bounce,
+        fastfail=m.fastfail + n_ff,
     )
     return s._replace(
         st=st, node=node, zone=zone, timer=timer, patience=patience, metrics=m

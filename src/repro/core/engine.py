@@ -47,6 +47,7 @@ TS_FIELDS = (
     "resumed_insitu",
     "migrated",
     "timeout",
+    "op_eval",
 )
 
 
@@ -411,6 +412,12 @@ def summarize(cfg: LaminarConfig, final: SimState, ts: np.ndarray) -> Dict[str, 
         + float(m.op_dispatch) * cfg.ns_zone_aggregate * 0.0  # cold path excluded
     )
 
+    # DAs addressed each tick, and the trips of da.address's block loop they
+    # took; the run starts from init_state, whose counters are zero
+    addressed = np.diff(ts[:, TS_FIELDS.index("op_eval")], prepend=0)
+    addr_max = int(addressed.max()) if addressed.size else 0
+    addr_block = da.address_block(cfg, st.shape[0])
+
     probe_drops = (
         int(m.fastfail)
         + int(m.lost)
@@ -479,6 +486,8 @@ def summarize(cfg: LaminarConfig, final: SimState, ts: np.ndarray) -> Dict[str, 
         p99_ms=p99,
         control_us_per_start=work_ns / started / 1e3,
         probe_drops=probe_drops,
+        addr_probes_per_tick_max=addr_max,
+        addr_trips_max=-(-addr_max // addr_block),
         lat_hist=hist,
         timeseries={f: ts[:, i] for i, f in enumerate(TS_FIELDS)},
     )

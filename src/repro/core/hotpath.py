@@ -41,9 +41,11 @@ from repro.kernels.bitmap_fit import ops as _bitmap_ops
 from repro.kernels.survival_scan import ops as _surv_ops
 from repro.kernels.survival_scan import ref as _surv_ref
 from repro.kernels.utility_topk import ops as _topk_ops
+from repro.kernels.utility_topk.kernel import BLOCK_P as TOPK_BLOCK
 from repro.kernels.zone_aggregate import ops as _agg_ops
 
 __all__ = [
+    "TOPK_BLOCK",
     "bitmap_fit",
     "bitmap_fit_blocked",
     "survival_scan",
