@@ -60,9 +60,13 @@ from repro.core.state import SimState, unpack_zoned
 from repro.workloads.scenario import ScenarioConfig
 
 AXIS = "zones"
+# every cross-shard exchange of the tick runs inside this scope, nested in the
+# stage (``laminar.<stage>``) that asks for it, so a trace reads it apart
+EXCHANGE_SCOPE = "zone_exchange"
 
 __all__ = [
     "AXIS",
+    "EXCHANGE_SCOPE",
     "MeshPlane",
     "ZoneShardedEngine",
     "traffic_model",
@@ -112,8 +116,9 @@ class MeshPlane:
 
     def _to_flat(self, x_l: jax.Array, s: SimState) -> jax.Array:
         """all_gather local (Zb, M, ...) blocks -> flat replicated (N, ...)."""
-        xb = jax.lax.all_gather(x_l, self.axis, axis=0, tiled=True)  # (Zp, M, ...)
-        return unpack_zoned(xb, s.zmember, s.zmask, self.cfg.num_nodes)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            xb = jax.lax.all_gather(x_l, self.axis, axis=0, tiled=True)  # (Zp, M, ...)
+            return unpack_zoned(xb, s.zmember, s.zmask, self.cfg.num_nodes)
 
     def _local_words(self, s: SimState, mem_l, msk_l) -> jax.Array:
         """(R, W) free bitmap words of the local rows; padding slots zeroed."""
@@ -190,8 +195,9 @@ class MeshPlane:
         mem_l, msk_l = self._local_rows(s)
         zS_l, zH_l = hotpath.zone_aggregate(cfg, s.rep_S[mem_l], s.rep_H[mem_l], msk_l)
         Z = s.zmember.shape[0]
-        zS = jax.lax.all_gather(zS_l, self.axis, axis=0, tiled=True)[:Z]
-        zH = jax.lax.all_gather(zH_l, self.axis, axis=0, tiled=True)[:Z]
+        with jax.named_scope(EXCHANGE_SCOPE):
+            zS = jax.lax.all_gather(zS_l, self.axis, axis=0, tiled=True)[:Z]
+            zH = jax.lax.all_gather(zH_l, self.axis, axis=0, tiled=True)[:Z]
         return zS, zH
 
 

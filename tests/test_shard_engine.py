@@ -15,7 +15,9 @@ Four layers:
      subprocess) the storm and bursty presets stay bit-for-bit identical
      for BOTH ``use_pallas`` dispatch modes — the cross-shard exchange is
      exact gathers of deterministically computed rows, so sharding must
-     never move a metric;
+     never move a metric; with 4 forced host devices Exp2's switches stay
+     bit-for-bit identical too, and every all-gather carries the
+     ``zone_exchange`` scope, which changes only the program's metadata;
 
   4. traffic model: the modeled control-plane exchange is O(num_zones)
      floats per tick, independent of num_nodes; the simulator-fidelity sync
@@ -27,6 +29,7 @@ Four layers:
 import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -44,7 +47,12 @@ from repro.core.state import (
     pack_zoned,
     unpack_zoned,
 )
-from repro.parallel.engine_mesh import ZoneShardedEngine, traffic_model, zone_mesh
+from repro.parallel.engine_mesh import (
+    EXCHANGE_SCOPE,
+    ZoneShardedEngine,
+    traffic_model,
+    zone_mesh,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -63,6 +71,7 @@ SMALL = LaminarConfig(
 # one maintained copy of the summarize() bit-for-bit comparison; only the
 # subprocess source string below is forced to inline its own standalone copy
 from test_hotpath import _assert_outputs_identical as assert_outputs_identical
+from test_stage_scopes import OP_NAME, stage_components, without_metadata
 
 
 # ---------------------------------------------------------------------------
@@ -286,6 +295,115 @@ def test_two_device_bitwise_parity_subprocess():
     checked = json.loads(out.stdout.strip().splitlines()[-1])
     assert len(checked) == 4  # 2 presets x 2 dispatch modes
     assert all(row[2] > 0 for row in checked)
+
+
+# Exp2's switches (two-phase off, regeneration on, hop loss 0.01, memory
+# and Airlock off) at a geometry whose zone rows do not divide by four
+_EXP2_SMALL = dict(
+    num_nodes=300, zone_size=32, probe_capacity=2048, max_arrivals_per_tick=128,
+    horizon_ms=40.0, rho=0.8, two_phase=False, regeneration=True, hop_loss=0.01,
+)
+
+_SUBPROCESS_FOUR = """
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import contextlib, json
+from pathlib import Path
+import numpy as np
+import jax
+assert len(jax.devices()) == 4, jax.devices()
+from repro.core import LaminarConfig, LaminarEngine
+from repro.parallel.engine_mesh import ZoneShardedEngine
+
+cfg = LaminarConfig(**json.loads(sys.argv[2]))
+out = Path(sys.argv[3])
+if sys.argv[1] == "parity":
+    flat = LaminarEngine(cfg)
+    s, lam = flat.init(0)
+    f_end, f_ts = flat._runner(lam, cfg.num_ticks)(s)
+    m_end, m_ts = ZoneShardedEngine(cfg, num_devices=4)._runner(lam, cfg.num_ticks)(s)
+    fields = 0
+    for name, a, b in zip(s._fields, f_end, m_end):
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y), err_msg=name)
+            fields += 1
+    np.testing.assert_array_equal(np.asarray(f_ts), np.asarray(m_ts))
+    print(json.dumps({"fields": fields, "zones": int(s.zmember.shape[0]),
+                      "arrived": int(f_end.metrics.arrived),
+                      "started": int(f_end.metrics.started)}))
+else:
+    def chunk_programs():
+        engine = ZoneShardedEngine(cfg, num_devices=4)
+        s, lam = engine.init(0)
+        lowered = engine._runner(lam, 1).lower(s)
+        return lowered.as_text(), lowered.compile().as_text()
+
+    texts = {"scoped": chunk_programs()}
+    real = jax.named_scope
+    jax.named_scope = lambda name: contextlib.nullcontext()
+    try:
+        texts["bare"] = chunk_programs()
+    finally:
+        jax.named_scope = real
+    for k, (ir, hlo) in texts.items():
+        (out / f"{k}.mlir").write_text(ir)
+        (out / f"{k}.hlo").write_text(hlo)
+    print(json.dumps(sorted(texts)))
+"""
+
+
+def _four_devices(mode: str, out_dir) -> dict:
+    """Run ``_SUBPROCESS_FOUR`` in ``mode`` on 4 forced host devices."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c", _SUBPROCESS_FOUR, mode, json.dumps(_EXP2_SMALL),
+         str(out_dir)],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_four_device_exp2_bitwise_parity_subprocess(tmp_path):
+    """Exp2's switches on 4 forced host devices: every table of the final
+    state, every counter and the per-tick series equal the flat engine's."""
+    got = _four_devices("parity", tmp_path)
+    assert got["zones"] % 4 != 0  # the last device holds padding rows
+    assert got["arrived"] > 0 and got["started"] > 0
+    assert got["fields"] > 30
+
+
+@pytest.fixture(scope="module")
+def four_device_programs(tmp_path_factory):
+    """(StableHLO, optimized HLO) of the 4-device sharded one-tick chunk,
+    with its scopes (``scoped``) and with ``jax.named_scope`` patched out
+    (``bare``)."""
+    out = tmp_path_factory.mktemp("four_device_programs")
+    _four_devices("programs", out)
+    return {k: ((out / f"{k}.mlir").read_text(), (out / f"{k}.hlo").read_text())
+            for k in ("scoped", "bare")}
+
+
+def test_four_device_exchange_carries_its_scope(four_device_programs):
+    hlo = four_device_programs["scoped"][1]
+    gathers = re.findall(r"= [^\n]* all-gather(?:-start)?\([^\n]*", hlo)
+    assert gathers
+    for line in gathers:
+        op_name = OP_NAME.search(line)
+        assert op_name and EXCHANGE_SCOPE in op_name.group(1).split("/"), line
+    names = OP_NAME.findall(hlo)
+    assert [n for n in names if len(stage_components(n)) > 1] == []
+
+
+def test_four_device_scopes_change_only_metadata(four_device_programs):
+    (scoped_ir, scoped), (bare_ir, bare) = (
+        four_device_programs["scoped"], four_device_programs["bare"])
+    assert any(EXCHANGE_SCOPE in n for n in OP_NAME.findall(scoped))
+    assert not any(EXCHANGE_SCOPE in n for n in OP_NAME.findall(bare))
+    assert scoped_ir == bare_ir
+    assert without_metadata(scoped) == without_metadata(bare)
 
 
 def test_zone_mesh_validates_device_count():
