@@ -28,9 +28,9 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import events
 from repro.core.config import LaminarConfig
 from repro.core.state import EMPTY, RUNNING, SUSPENDED, SimState, tier_counts
-from repro.core.arbiter import _free_atoms_at
 
 
 def memory_dynamics(cfg: LaminarConfig, s: SimState, key: jax.Array) -> SimState:
@@ -70,7 +70,7 @@ def runtime_control(
     if not cfg.airlock:
         # kernel OOM: destroy outright (badness ~ memory footprint) --
         # indiscriminate, kills L-tasks.
-        free = _free_atoms_at(s.free, s.alloc, s.alloc_node, victim)
+        free = events.release(cfg, s.free, victim, [(s.alloc, s.alloc_node)])
         m = s.metrics
         m = m._replace(
             oom_kill_f=m.oom_kill_f + jnp.sum((victim & ~s.contig).astype(jnp.int32)),
@@ -131,8 +131,11 @@ def airlock_transitions(
     patience = jnp.where(react, s.ev, s.patience)  # fresh E_patience budget
     surv_deadline = jnp.where(react, s.t + cfg.ticks(cfg.t_surv_ms), s.surv_deadline)
 
-    free = _free_atoms_at(s.free, s.alloc, s.alloc_node, expire)
-    free = _free_atoms_at(free, s.alloc2, s.node2, expire & (s.node2 >= 0))
+    # both the primary allocation and any destination reservation (node2
+    # is -1 where there is none)
+    free = events.release(
+        cfg, s.free, expire, [(s.alloc, s.alloc_node), (s.alloc2, s.node2)]
+    )
     st = jnp.where(expire, EMPTY, st)
 
     m = s.metrics

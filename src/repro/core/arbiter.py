@@ -25,7 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import bitmap, hotpath
+from repro.core import bitmap, events, hotpath
 from repro.core.config import LaminarConfig
 from repro.core.state import (
     ADDRESSING,
@@ -34,22 +34,10 @@ from repro.core.state import (
     RESERVED,
     RUNNING,
     SimState,
-    latency_bucket,
     tier_counts,
 )
 
 INF_TICK = jnp.int32(1 << 30)
-
-
-def _free_atoms_at(
-    free: jax.Array, alloc: jax.Array, node: jax.Array, mask: jax.Array
-) -> jax.Array:
-    """Return freed node bitmap words: free |= alloc for each masked probe."""
-    upd = jnp.where(mask[:, None], alloc, jnp.uint32(0))
-    tgt = jnp.where(mask, node, free.shape[0])  # OOB rows dropped
-    acc = jnp.zeros((free.shape[0] + 1, free.shape[1]), jnp.uint32)
-    acc = acc.at[tgt].add(upd)  # held allocations are disjoint -> add == or
-    return free | acc[:-1]
 
 
 def arbitrate(
@@ -207,10 +195,12 @@ def pending_stage(cfg: LaminarConfig, s: SimState) -> SimState:
     patience = jnp.where(start_now, s.patience + s.deposit, s.patience)  # unfreeze
     deposit = jnp.where(start_now, 0.0, s.deposit)
 
-    free, alloc, alloc_node = s.free, s.alloc, s.alloc_node
+    alloc, alloc_node = s.alloc, s.alloc_node
     alloc2, node2 = s.alloc2, s.node2
     migrating = s.migrating
     m = s.metrics
+    prim_exp = expired & ~s.migrating
+    frees = prim_exp  # the slots whose ``alloc`` returns to the free words
 
     # ---- migration landing: new execution epoch recognized --------------
     if airlock_on:
@@ -218,8 +208,7 @@ def pending_stage(cfg: LaminarConfig, s: SimState) -> SimState:
         mig_late = done & s.migrating & (s.t > s.surv_deadline)
         mig_fail = (expired & s.migrating) | mig_late
         # source freed on success; both sides freed on bounded reclamation
-        free = _free_atoms_at(free, s.alloc, s.alloc_node, mig_ok | mig_fail)
-        free = _free_atoms_at(free, s.alloc2, s.node2, mig_fail)
+        frees = frees | mig_ok | mig_fail
         alloc = jnp.where(mig_ok[:, None], s.alloc2, alloc)
         alloc = jnp.where(mig_fail[:, None], jnp.uint32(0), alloc)
         alloc_node = jnp.where(mig_ok, s.node2, alloc_node)
@@ -237,10 +226,8 @@ def pending_stage(cfg: LaminarConfig, s: SimState) -> SimState:
 
     # ---- primary reservation expiry --------------------------------------
     # restore bitmap, forfeit deposit, re-address (or dissipate)
-    prim_exp = expired & ~s.migrating
     squat_exp = prim_exp & s.squat
     retry = prim_exp & ~s.squat
-    free = _free_atoms_at(free, s.alloc, s.alloc_node, prim_exp)
     alloc = jnp.where(prim_exp[:, None], jnp.uint32(0), alloc)
     alloc_node = jnp.where(prim_exp, -1, alloc_node)
     deposit = jnp.where(prim_exp, 0.0, deposit)  # forfeited
@@ -248,15 +235,29 @@ def pending_stage(cfg: LaminarConfig, s: SimState) -> SimState:
     st = jnp.where(retry & (patience < cfg.fastfail_floor), EMPTY, st)
     st = jnp.where(squat_exp, EMPTY, st)
 
-    # ---- metrics ----------------------------------------------------------
-    lat_ms = (s.t - s.arrival).astype(jnp.float32) * cfg.dt_ms
-    bucket = latency_bucket(lat_ms)
-    hist = m.lat_hist.at[jnp.where(start_now, bucket, 0)].add(
-        start_now.astype(jnp.int32)
+    # ---- freed atoms and latency histograms --------------------------------
+    # every row reads the stage's incoming allocations, so the expiries and
+    # landings share one accumulator of freed words
+    def add_events(row, on, carry):
+        acc, hists = carry
+        acc = events.add_freed(acc, s.alloc[row], s.alloc_node[row], on & frees[row])
+        if airlock_on:
+            fail = on & mig_fail[row]
+            acc = events.add_freed(acc, s.alloc2[row], s.node2[row], fail)
+        hists = events.count_latency(
+            cfg, s.t, s.arrival[row], s.tier[row], on & start_now[row], hists
+        )
+        return acc, hists
+
+    acc, (hist, hist_tier) = events.accumulate(
+        cfg,
+        frees | start_now,
+        add_events,
+        (events.no_freed(s.free), (m.lat_hist, m.lat_hist_tier)),
     )
-    hist_tier = m.lat_hist_tier.at[
-        jnp.where(start_now, s.tier, 0), jnp.where(start_now, bucket, 0)
-    ].add(start_now.astype(jnp.int32))
+    free = s.free | acc[:-1]
+
+    # ---- metrics ----------------------------------------------------------
     m = m._replace(
         started=m.started + jnp.sum(start_now.astype(jnp.int32)),
         started_f=m.started_f + jnp.sum((start_now & ~s.contig).astype(jnp.int32)),
@@ -289,7 +290,7 @@ def completions(cfg: LaminarConfig, s: SimState) -> SimState:
     service = jnp.where(running, s.service - 1, s.service)
     done = running & (service <= 0)
 
-    free = _free_atoms_at(s.free, s.alloc, s.alloc_node, done)
+    free = events.release(cfg, s.free, done, [(s.alloc, s.alloc_node)])
     m = s.metrics
     n_done = jnp.sum(done.astype(jnp.int32))
     m = m._replace(
@@ -317,7 +318,7 @@ def timeouts(cfg: LaminarConfig, s: SimState) -> SimState:
     control = (((s.st > EMPTY) & (s.st < RUNNING)) | (s.st == LOST_WAIT)) & ~s.migrating
     late = control & ((s.t - s.arrival) > cfg.ticks(cfg.task_timeout_ms))
     # RESERVED probes may hold atoms: restore
-    free = _free_atoms_at(s.free, s.alloc, s.alloc_node, late)
+    free = events.release(cfg, s.free, late, [(s.alloc, s.alloc_node)])
     m = s.metrics
     m = m._replace(timeout=m.timeout + jnp.sum(late.astype(jnp.int32)))
     return s._replace(

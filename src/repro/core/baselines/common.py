@@ -16,14 +16,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import bitmap, workload
+from repro.core import bitmap, events, workload
 from repro.core.config import NUM_TIERS, TIER_NAMES, BaselineConfig, LaminarConfig
 from repro.core.disrupt import disrupted_capacity
 from repro.core.state import (
     HIST_BUCKETS,
     hist_quantile,
     init_state,
-    latency_bucket,
     tier_counts,
 )
 from repro.workloads import schedule as wl_schedule
@@ -313,12 +312,13 @@ def admit_fifo(
         alloc=jnp.where(admit[:, None], probe_alloc, tt.alloc),
         alloc_node=jnp.where(admit, tt.node, tt.alloc_node),
     )
-    lat_ms = (t - tt.arrival).astype(jnp.float32) * cfg.dt_ms
-    b = latency_bucket(lat_ms)
-    hist = m.lat_hist.at[jnp.where(admit, b, 0)].add(admit.astype(jnp.int32))
-    hist_tier = m.lat_hist_tier.at[
-        jnp.where(admit, tt.tier, 0), jnp.where(admit, b, 0)
-    ].add(admit.astype(jnp.int32))
+
+    def add_starts(row, on, hists):
+        return events.count_latency(cfg, t, tt.arrival[row], tt.tier[row], on, hists)
+
+    hist, hist_tier = events.accumulate(
+        cfg, admit, add_starts, (m.lat_hist, m.lat_hist_tier)
+    )
     m = m._replace(
         started=m.started + jnp.sum(admit.astype(jnp.int32)),
         started_tier=m.started_tier + tier_counts(tt.tier, admit),
@@ -332,10 +332,7 @@ def complete(cfg: LaminarConfig, tt: TaskTable, free: jax.Array, m: BaseMetrics)
     running = tt.st == B_RUNNING
     service = jnp.where(running, tt.service - 1, tt.service)
     done = running & (service <= 0)
-    upd = jnp.where(done[:, None], tt.alloc, jnp.uint32(0))
-    tgt = jnp.where(done, tt.alloc_node, cfg.num_nodes)
-    acc = jnp.zeros((cfg.num_nodes + 1, free.shape[1]), jnp.uint32).at[tgt].add(upd)
-    free = free | acc[:-1]
+    free = events.release(cfg, free, done, [(tt.alloc, tt.alloc_node)])
     m = m._replace(
         completed=m.completed + jnp.sum(done.astype(jnp.int32)),
         completed_tier=m.completed_tier + tier_counts(tt.tier, done),

@@ -24,7 +24,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import hotpath, zhaf
+from repro.core import events, hotpath, zhaf
 from repro.core.config import LaminarConfig
 from repro.core.state import (
     ADDRESSING,
@@ -98,17 +98,6 @@ def move(cfg: LaminarConfig, s: SimState, key: jax.Array) -> Tuple[SimState, jax
 
     s = s._replace(st=st, timer=timer, regen=regen, patience=patience, metrics=m)
     return s, can_regen
-
-
-def address_block(cfg: LaminarConfig, num_slots: int) -> int:
-    """Slots ``address`` decides per trip of its block loop.
-
-    The smallest multiple of the utility_topk kernel's probe block that
-    holds twice a tick's arrival cap, capped at the table: one trip covers
-    a steady tick's addressing DAs, and a busier tick takes more trips.
-    """
-    want = max(2 * cfg.max_arrivals_per_tick, 1)
-    return min(-(-want // hotpath.TOPK_BLOCK) * hotpath.TOPK_BLOCK, num_slots)
 
 
 def _decide(
@@ -192,10 +181,10 @@ def address(
     feasibility mask (S / max-run vs demand) prunes false candidates. If j* is
     the launchpad we enqueue locally; otherwise one physical bounce.
 
-    Only the addressing slots are decided: their indices are compacted from
-    a cumulative count and decided ``address_block`` at a time, as many
-    trips as the count needs. The random numbers are drawn for the whole
-    table, so every slot sees the numbers it would see were all decided.
+    Only the addressing slots are decided: ``events.for_rows`` hands them
+    over ``events.row_block`` at a time, as many trips as their count needs.
+    The random numbers are drawn for the whole table, so every slot sees the
+    numbers it would see were all decided.
     """
     P = s.st.shape[0]
     k = cfg.candidate_k
@@ -203,34 +192,23 @@ def address(
     r = jax.random.uniform(k_cand, (P, k - 1))
     eps = cfg.addr_noise_sigma * jax.random.normal(k_noise, (P, k))
 
-    rank = jnp.cumsum((s.st == ADDRESSING).astype(jnp.int32))
-    n_active = rank[-1]
-    C = address_block(cfg, P)
-    lane_rank = jnp.arange(1, C + 1, dtype=jnp.int32)
-
-    def more(carry):
-        return carry[0] * C < n_active
-
-    def trip(carry):
-        b, fields, n_bounce, n_ff = carry
-        # the slot of each rank in this block; P past the last addressing DA
-        slot = jnp.searchsorted(
-            rank, b * C + lane_rank, side="left", method="scan_unrolled"
-        ).astype(jnp.int32)
-        valid = slot < P
-        row = jnp.minimum(slot, P - 1)  # gathers only; scatters drop P
+    def trip(row, on, carry):
+        fields, n_active, n_bounce, n_ff = carry
         new, bounce, ff = _decide(cfg, s, view, row, r[row], eps[row])
-        fields = tuple(
-            f.at[slot].set(v, mode="drop") for f, v in zip(fields, new)
-        )
-        n_bounce = n_bounce + jnp.sum((valid & bounce & ~ff).astype(jnp.int32))
-        n_ff = n_ff + jnp.sum((valid & ff).astype(jnp.int32))
-        return b + 1, fields, n_bounce, n_ff
+        tgt = jnp.where(on, row, P)  # scatters drop the lanes that are off
+        fields = tuple(f.at[tgt].set(v, mode="drop") for f, v in zip(fields, new))
+        n_active = n_active + jnp.sum(on.astype(jnp.int32))
+        n_bounce = n_bounce + jnp.sum((on & bounce & ~ff).astype(jnp.int32))
+        n_ff = n_ff + jnp.sum((on & ff).astype(jnp.int32))
+        return fields, n_active, n_bounce, n_ff
 
     zero = jnp.zeros((), jnp.int32)
     fields = (s.st, s.node, s.zone, s.timer, s.patience)
-    _, fields, n_bounce, n_ff = jax.lax.while_loop(
-        more, trip, (zero, fields, zero, zero)
+    fields, n_active, n_bounce, n_ff = events.for_rows(
+        s.st == ADDRESSING,
+        events.row_block(cfg, P),
+        trip,
+        (fields, zero, zero, zero),
     )
     st, node, zone, timer, patience = fields
 

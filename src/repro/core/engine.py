@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import airlock, arbiter, da, disrupt, hotpath, teg, workload, zhaf
+from repro.core import airlock, arbiter, da, disrupt, events, hotpath, teg, workload, zhaf
 from repro.core.config import LaminarConfig
 from repro.workloads import schedule as wl_schedule
 from repro.workloads.scenario import ScenarioConfig
@@ -416,7 +416,7 @@ def summarize(cfg: LaminarConfig, final: SimState, ts: np.ndarray) -> Dict[str, 
     # took; the run starts from init_state, whose counters are zero
     addressed = np.diff(ts[:, TS_FIELDS.index("op_eval")], prepend=0)
     addr_max = int(addressed.max()) if addressed.size else 0
-    addr_block = da.address_block(cfg, st.shape[0])
+    addr_block = events.row_block(cfg, st.shape[0])
 
     probe_drops = (
         int(m.fastfail)

@@ -118,11 +118,10 @@ METRIC_VECTOR_FIELDS = (
 
 
 def tier_counts(tier: jax.Array, mask: jax.Array) -> jax.Array:
-    """Count masked probes per tier -> (NUM_TIERS,) i32 scatter-add."""
-    tgt = jnp.where(mask, tier, NUM_TIERS)
-    return jnp.zeros((NUM_TIERS,), jnp.int32).at[tgt].add(
-        mask.astype(jnp.int32), mode="drop"
-    )
+    """Count masked probes per tier -> (NUM_TIERS,) i32: a compare and a
+    sum per tier, no scatter."""
+    hit = (tier[:, None] == jnp.arange(NUM_TIERS, dtype=tier.dtype)) & mask[:, None]
+    return jnp.sum(hit.astype(jnp.int32), axis=0)
 
 
 HIST_BUCKETS = 64

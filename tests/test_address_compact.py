@@ -1,7 +1,7 @@
 """DA addressing decides only the addressing slots, and decides them exactly.
 
 ``da.address`` compacts the slots with ``st == ADDRESSING`` and decides them
-``da.address_block`` at a time. ``full_table_address`` below is the
+``events.row_block`` at a time. ``full_table_address`` below is the
 addressing round that decides every slot of the table and keeps the
 results of the addressing ones; the two must return the same ``SimState``,
 every field and counter bit for bit, whatever the number of addressing
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import LaminarConfig, LaminarEngine, da, hotpath, zhaf
+from repro.core import LaminarConfig, LaminarEngine, da, events, hotpath, zhaf
 from repro.core.engine import summarize
 from repro.core.state import ADDRESSING, BOUNCING, EMPTY, QUEUED, SUSPENDED, SimState
 from repro.core.utility import unified_utility
@@ -31,7 +31,7 @@ CFG = LaminarConfig(
     rho=0.8,
 )
 P = CFG.probe_capacity
-C = da.address_block(CFG, P)
+C = events.row_block(CFG, P)
 
 
 def full_table_address(cfg, s, key, view):
@@ -173,11 +173,11 @@ def test_compacted_address_equals_full_table(warm_state, case, pallas):
 
 
 def test_address_block_is_derived_from_the_arrival_cap():
-    assert da.address_block(CFG, P) == 1024
+    assert events.row_block(CFG, P) == 1024
     big = dataclasses.replace(CFG, max_arrivals_per_tick=1664)
-    assert da.address_block(big, 131072) == 4096
-    assert da.address_block(big, 65536) == 4096
-    assert da.address_block(big, 2048) == 2048  # capped at the table
+    assert events.row_block(big, 131072) == 4096
+    assert events.row_block(big, 65536) == 4096
+    assert events.row_block(big, 2048) == 2048  # capped at the table
 
 
 def test_run_batch_seeds_with_different_trip_counts_match_their_runs():
@@ -191,7 +191,7 @@ def test_run_batch_seeds_with_different_trip_counts_match_their_runs():
         horizon_ms=40.0,
         rho=20.0,
     )
-    block = da.address_block(cfg, cfg.probe_capacity)
+    block = events.row_block(cfg, cfg.probe_capacity)
     seeds = [0, 1, 2]
     engine = LaminarEngine(cfg)
     outs = engine.run_batch(seeds)
